@@ -10,6 +10,7 @@ machine-readable ``results/BENCH_sodda.json`` (schema in
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import dataclasses
 import json
@@ -32,6 +33,31 @@ def _t(fn, *args, reps=3):
     for _ in range(reps):
         jax.block_until_ready(fn(*args))
     return (time.perf_counter() - t0) / reps * 1e6  # us
+
+
+class AcceleratorHeldError(RuntimeError):
+    """A cell that runs in child processes on forced CPU host devices was
+    started in a process that holds an accelerator."""
+
+
+def cpu_children_only(cell):
+    """Refuse `cell` on an accelerator host before it spawns anything.
+
+    The cell's children force CPU host devices, and on a chip host this
+    process already holds the chip, so a child that needs it would fail or
+    hang. One process per chip: `chip_smoke.py` is the path that runs there.
+    """
+    @functools.wraps(cell)
+    def guarded(*args, **kwargs):
+        plat = jax.default_backend()
+        if plat != "cpu":
+            raise AcceleratorHeldError(
+                f"{cell.__name__} spawns child processes on forced CPU host "
+                f"devices; this process holds the {plat} device, so it "
+                "runs on a CPU host only")
+        return cell(*args, **kwargs)
+
+    return guarded
 
 
 ROWS = []
@@ -163,6 +189,7 @@ def bench_kernels():
 # ---------------------------------------------------------------------------
 # Distributed SODDA step benches (12 fake devices) — communication profile.
 # ---------------------------------------------------------------------------
+@cpu_children_only
 def bench_distributed_sodda():
     import subprocess, sys, os, json
     script = r"""
@@ -432,6 +459,7 @@ print(json.dumps({
 """
 
 
+@cpu_children_only
 def run_large_cell(iters: int = LARGE_ITERS_DEFAULT, timeout: int = 1200):
     """Run the Table-1-sized tiled cell in a fresh 15-device subprocess and
     return its ``large_problem`` payload dict (see validate_bench)."""
@@ -448,6 +476,7 @@ def run_large_cell(iters: int = LARGE_ITERS_DEFAULT, timeout: int = 1200):
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
+@cpu_children_only
 def bench_driver_large(iters: int = LARGE_ITERS_DEFAULT, out_path: str = None,
                        force: bool = False):
     """The ROADMAP "Large-problem BENCH trend tracking" cell: Table-1-sized
@@ -551,6 +580,7 @@ print(json.dumps({
 """
 
 
+@cpu_children_only
 def run_streaming_cell(iters: int = STREAM_ITERS_DEFAULT,
                        segment_iters: int = STREAM_SEGMENT_DEFAULT,
                        timeout: int = 1200):
@@ -570,6 +600,7 @@ def run_streaming_cell(iters: int = STREAM_ITERS_DEFAULT,
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
+@cpu_children_only
 def bench_streaming(iters: int = STREAM_ITERS_DEFAULT,
                     segment_iters: int = STREAM_SEGMENT_DEFAULT,
                     out_path: str = None):
@@ -725,7 +756,6 @@ def bench_tuning(reps: int = 5, out_path: str = None):
 
     def time_config(config, n_reps=reps):
         return _t(lambda: ops.sodda_inner(w0, Xl, yl, mu, 0.05, loss,
-                                          force="pallas",
                                           block_l=config.block_l),
                   reps=n_reps)
 
@@ -826,6 +856,7 @@ print(json.dumps(out))
 """
 
 
+@cpu_children_only
 def run_multihost_cell(iters: int = MULTIHOST_ITERS_DEFAULT, reps: int = 3,
                        num_processes: int = MULTIHOST_PROCESSES_DEFAULT,
                        timeout: int = 1200):
@@ -874,6 +905,7 @@ def run_multihost_cell(iters: int = MULTIHOST_ITERS_DEFAULT, reps: int = 3,
     return block
 
 
+@cpu_children_only
 def bench_multihost(iters: int = MULTIHOST_ITERS_DEFAULT, reps: int = 3,
                     out_path: str = None):
     """The 2-process mesh smoke cell, merged into BENCH_sodda.json as the
@@ -969,6 +1001,7 @@ print(json.dumps({
 """
 
 
+@cpu_children_only
 def run_multihost_large_cell(iters: int = MULTIHOST_LARGE_ITERS_DEFAULT,
                              timeout: int = 5400):
     """Run the 250k x 18k Table-1 cell on 5 coordinated processes (3 devices
@@ -1005,6 +1038,7 @@ def run_multihost_large_cell(iters: int = MULTIHOST_LARGE_ITERS_DEFAULT,
     }
 
 
+@cpu_children_only
 def bench_multihost_large(iters: int = MULTIHOST_LARGE_ITERS_DEFAULT,
                           out_path: str = None, force: bool = False):
     """The paper-scale 250k x 18k multi-process cell, merged into
@@ -1084,6 +1118,7 @@ def main(argv=None) -> None:
     # centralizes the latency-hiding XLA flags / env for the bench host;
     # must precede the first jax backend touch in the benched functions
     repro_platform.configure()
+    repro_platform.use_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(BENCHES))
     args = ap.parse_args(argv)

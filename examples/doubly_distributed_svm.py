@@ -18,12 +18,14 @@ import time
 
 import jax
 
+from repro import platform as repro_platform
 from repro.configs.sodda_svm import SoddaConfig
 from repro.core import driver, engine
 from repro.data.plane import TiledDataPlane
 
 
 def main():
+    repro_platform.use_compilation_cache()
     cfg = SoddaConfig(P=4, Q=3, n=2000, m=300, L=32, lr0=0.05)
     print(f"devices: {len(jax.devices())}; grid P={cfg.P} x Q={cfg.Q}")
     mesh = engine.make_mesh_for(cfg)

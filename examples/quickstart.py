@@ -12,6 +12,7 @@ import time
 
 import jax
 
+from repro import platform as repro_platform
 from repro.configs.sodda_svm import SoddaConfig
 from repro.core import driver, radisa, sodda
 from repro.data.synthetic import make_svm_data
@@ -26,6 +27,7 @@ def main(argv=None):
     ap.add_argument("--m", type=int, default=600)
     ap.add_argument("--L", type=int, default=32)
     args = ap.parse_args(argv)
+    repro_platform.use_compilation_cache()
 
     cfg = SoddaConfig(P=args.P, Q=args.Q, n=args.n, m=args.m, L=args.L,
                       lr0=0.05, b_frac=0.85, c_frac=0.80, d_frac=0.85)

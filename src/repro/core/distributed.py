@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.sodda_svm import SoddaConfig
 from repro.core import losses
 from repro.core.partition import _exact_count_mask
@@ -132,8 +131,7 @@ def make_local_halves(cfg: SoddaConfig, gather_deltas: bool = True,
         if use_kernel:
             from repro.kernels import ops as kops  # local import: optional dep
             wL = kops.sodda_inner(w0[None], Xl[None], yl[None], mu_blk[None],
-                                  gamma, cfg.loss, force="pallas",
-                                  block_l=block_l)[0]
+                                  gamma, cfg.loss, block_l=block_l)[0]
         else:
             wL = inner_loop(cfg.loss, w0, Xl, yl, mu_blk, gamma)
 
@@ -187,7 +185,7 @@ def make_distributed_step(mesh, cfg: SoddaConfig, gather_deltas: bool = True,
         mu_q = issue_local(X_loc, y_loc, w_loc, t, key)
         return consume_local(X_loc, y_loc, w_loc, mu_q, t, key)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step_local,
         mesh=mesh,
         in_specs=(P("data", "model"), P("data"), P("model"), P(), P()),
@@ -258,7 +256,7 @@ def make_distributed_async_step(mesh, cfg: SoddaConfig, staleness: int = 1,
         w_new = consume_local(X_loc, y_loc, w_loc, mu_consumed, t, key)
         return w_new, mu_issued
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step_local,
         mesh=mesh,
         in_specs=(P("data", "model"), P("data"), P("model"), P("model"),
@@ -273,12 +271,12 @@ def make_distributed_async_step(mesh, cfg: SoddaConfig, staleness: int = 1,
     # and an un-jitted shard_map dispatch executes op-by-op (three orders of
     # magnitude slower on a fake multi-device host); inside the scan
     # driver's compiled program the jit wrapper simply inlines
-    issue_smapped = jax.jit(shard_map(
+    issue_smapped = jax.jit(jax.shard_map(
         issue_local,
         mesh=mesh,
         in_specs=(P("data", "model"), P("data"), P("model"), P(), P()),
         out_specs=P("model"),
-        check_vma=False if (compress_mu or compress_z) else None,
+        check_vma=not (compress_mu or compress_z),
     ))
 
     @jax.jit
@@ -340,7 +338,7 @@ def distributed_objective(mesh, cfg: SoddaConfig):
         # replicated scalar out
         return v
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         obj_local, mesh=mesh,
         in_specs=(P("data", "model"), P("data"), P("model")),
         out_specs=P(),

@@ -247,7 +247,8 @@ def make_mesh_for(cfg: SoddaConfig):
             f"cfg grid {cfg.P}x{cfg.Q} needs {need} devices, have {have} "
             f"across {jax.process_count()} process(es) "
             "(force more with --xla_force_host_platform_device_count)")
-    return jax.make_mesh((cfg.P, cfg.Q), ("data", "model"))
+    return jax.make_mesh((cfg.P, cfg.Q), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _resolve_mesh(cfg: SoddaConfig, opts: EngineOptions):

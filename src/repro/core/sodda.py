@@ -160,8 +160,7 @@ def consume_update(X, y, w, mu, smp: IterationSample, gamma,
         wL = kops.sodda_inner(
             w0.reshape(P * Q, mt), Xl.reshape(P * Q, L, mt),
             yl.reshape(P * Q, L), mu_blk.reshape(P * Q, mt),
-            gamma, cfg.loss, force="pallas",
-            block_l=block_l).reshape(P, Q, mt)
+            gamma, cfg.loss, block_l=block_l).reshape(P, Q, mt)
     else:
         wL = jax.vmap(jax.vmap(
             lambda w_, X_, y_, m_: inner_loop(cfg.loss, w_, X_, y_, m_, gamma)

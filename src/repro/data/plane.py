@@ -270,9 +270,13 @@ class DataPlane(abc.ABC):
         for device, (rows, cols) in index_map.items():
             p = (rows.start or 0) // self.n
             q = (cols.start or 0) // self.m
-            if p not in y_cache:
-                y_cache[p] = self.y_block(p)
-            x_parts.append(jax.device_put(self.x_tile(p, q), device))
+            # generate on the tile's own device: staging every tile on the
+            # default device first would hold two tiles plus a temporary
+            # there, which at 4.5 GB tiles no longer fits a 16 GB chip
+            with jax.default_device(device):
+                if p not in y_cache:
+                    y_cache[p] = self.y_block(p)
+                x_parts.append(jax.device_put(self.x_tile(p, q), device))
             y_parts.append(jax.device_put(y_cache[p], device))
         X = jax.make_array_from_single_device_arrays(
             (self.N, self.M), x_sharding, x_parts)

@@ -137,10 +137,7 @@ def initialize(coordinator_address: Optional[str] = None,
     # (the option only affects the CPU client). Checking the platform via
     # jax.default_backend() would itself start the backend, so set it
     # unconditionally.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - old jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=nproc, process_id=pid)
     _INITIALIZED = (coord, nproc, pid)
@@ -286,7 +283,6 @@ def connect_mesh_collectives(mesh) -> None:
     import jax
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     names = tuple(mesh.axis_names)
     spec = P(*names)
@@ -298,7 +294,7 @@ def connect_mesh_collectives(mesh) -> None:
             acc = acc + jax.lax.psum(t, ax)
         return acc + jax.lax.psum(t, names)
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec))
     jax.block_until_ready(f(put_sharded(ones, NamedSharding(mesh, spec))))
 
 

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Padding/alignment and backend dispatch live here: on TPU the Pallas kernels
-compile natively; on CPU (this container) they run in interpret mode when
-explicitly requested (tests) and otherwise fall back to the pure-jnp
-references in ``ref.py`` (which the dry-run lowers — same math, same shapes).
+Padding/alignment and backend dispatch live here. The SODDA inner kernel
+always runs: compiled by Mosaic on TPU, in interpret mode elsewhere
+(`repro.platform.interpret_default`), never swapped for its jnp oracle. The
+language-model kernels compile on TPU and otherwise fall back to the
+pure-jnp references in ``ref.py`` unless ``force="pallas"``.
 """
 from __future__ import annotations
 
@@ -33,19 +34,15 @@ def _pad_axis(x, axis: int, mult: int):
 
 
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit,
-                   static_argnames=("loss", "force", "block_l", "interpret"))
-def sodda_inner(w0, Xl, yl, mu, gamma, loss: str = "hinge",
-                force: str = "auto", block_l=None, interpret=None):
+@functools.partial(jax.jit, static_argnames=("loss", "block_l", "interpret"))
+def sodda_inner(w0, Xl, yl, mu, gamma, loss: str = "hinge", block_l=None,
+                interpret=None):
     """Batched SODDA inner loop. w0 (B,mt), Xl (B,L,mt), yl (B,L), mu (B,mt).
 
     `block_l` is the L-tiling schedule (`tuning.BlockConfig.block_l`;
     None = single tile). `interpret=None` derives from `repro.platform`
     inside `sodda_inner_pallas` — it is threaded, never pinned here.
     """
-    use_kernel = force == "pallas" or (force == "auto" and _on_tpu())
-    if not use_kernel:
-        return ref.sodda_inner_ref(w0, Xl, yl, mu, gamma, loss)
     mt = w0.shape[-1]
     w0p, pad = _pad_axis(w0, 1, 128)
     Xlp, _ = _pad_axis(Xl, 2, 128)

@@ -6,7 +6,11 @@ module owns the schedule side of that contract:
 
 * **Legality** — a config is legal iff `block_l` divides L, the kernel's
   mt is lane-aligned (multiple of 128; `ops.sodda_inner` pads before the
-  kernel sees it), and the per-program VMEM footprint fits the budget.
+  kernel sees it), and the per-program VMEM footprint, padded to Mosaic's
+  (8, 128) tiles, fits the budget. The kernel's 4-D blocks span their
+  arrays' last two dimensions, so Mosaic's block-shape rule holds for every
+  divisor of L; everything the TPU compiler refuses is refused here first
+  (compiled against a described v5e in tests/test_tpu_compile.py).
   Illegal configs are refused with the named errors `AlignmentError` /
   `VmemBudgetError` (both `KernelTuningError`), never silently clamped.
 * **Scoring** — `predicted_time_s` prices each legal config with the
@@ -43,7 +47,8 @@ from repro import platform as repro_platform
 from repro.launch import roofline
 
 LANE = 128  # TPU lane width: the kernel's mt axis must align to this
-VMEM_BYTES = 16 * 2 ** 20  # per-core VMEM (v5e)
+SUBLANE = 8  # f32 rows per VMEM tile
+VMEM_BYTES = 16 * 2 ** 20  # Mosaic's default scoped VMEM limit on v5e
 # Fraction of VMEM the kernel may plan for; the rest is headroom for
 # compiler temporaries and semaphores.
 VMEM_BUDGET = int(VMEM_BYTES * 0.75)
@@ -91,15 +96,19 @@ def padded_mt(mt: int) -> int:
 def vmem_bytes(config: BlockConfig, L: int, mt: int) -> int:
     """Per-program VMEM plan for `config` on an (L, mt) block (f32).
 
-    Double-buffered streams (X tile + y tile; Pallas overlaps the next
-    tile's copy with this tile's compute) + the resident w0/mu/wbar
-    vectors + the per-tile z0/d0 margin scratch.
+    Mosaic stores every VMEM buffer in (8, 128) tiles, so rows round up to
+    the sublane count and a ``(block_l, 1)`` column takes a full lane
+    tile. Double-buffered streams (X tile + label column; Pallas overlaps
+    the next tile's copy with this tile's compute) + the double-buffered
+    ``(1, mt)`` w0/mu/out blocks (out carries the running wbar) + the
+    per-tile ``d0`` margin column scratch.
     """
     mtp = padded_mt(mt)
-    x_stream = 2 * config.block_l * mtp * 4
-    y_stream = 2 * config.block_l * 4
-    resident = 3 * mtp * 4  # w0, mu, out (the running wbar)
-    margins = 2 * config.block_l * 4  # z0, d0
+    rows = config.block_l + (-config.block_l) % SUBLANE
+    x_stream = 2 * rows * mtp * 4
+    y_stream = 2 * rows * LANE * 4
+    resident = 3 * 2 * SUBLANE * mtp * 4
+    margins = rows * LANE * 4
     return x_stream + y_stream + resident + margins
 
 
@@ -274,13 +283,12 @@ def _main(argv=None) -> int:
     parser.add_argument("--L", type=int, default=64)
     parser.add_argument("--mt", type=int, default=512)
     parser.add_argument("--platform", default=None,
-                        help="cpu|gpu|tpu (default: the active jax backend)")
+                        help="cpu|gpu|tpu (default: the first of "
+                             "JAX_PLATFORMS, else cpu)")
     parser.add_argument("--cache-dir", default=None)
     args = parser.parse_args(argv)
 
-    plat = args.platform
-    if plat is None:
-        plat = os.environ.get("REPRO_PLATFORM", "cpu")
+    plat = args.platform or repro_platform.declared_platform() or "cpu"
     config = autotune(args.loss, args.L, args.mt, platform=plat,
                       cache_dir=args.cache_dir)
     report = {

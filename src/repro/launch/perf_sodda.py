@@ -49,8 +49,6 @@ def analyze(cfg: SoddaConfig, gather: bool, compress: bool,
     with mesh:
         comp = run.lower(state, X, y).compile()
     cost = comp.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4: one dict per computation
-        cost = cost[0] if cost else {}
     stats = collective_stats(comp.as_text(), cfg.P * cfg.Q)
     # XLA's cost analysis and the HLO text both count the scan body ONCE
     # regardless of trip count, so these are already per-outer-iteration.

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, round_up
 from repro.kernels import ops as kops
 from repro.models.layers import apply_rope
@@ -156,7 +155,7 @@ def decode_attn_seq(p, h, cfg: ArchConfig, cache_k, cache_v, pos, mesh,
         n_b *= mesh.shape[a]
     if q.shape[0] % n_b:
         b = None
-    out, cache_k, cache_v = shard_map(
+    out, cache_k, cache_v = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b), P(b, axis), P(b, axis), P(b), P(b), P(b)),
         out_specs=(P(b), P(b, axis), P(b, axis)),

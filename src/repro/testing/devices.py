@@ -30,12 +30,9 @@ def force_host_devices(n: int = DEFAULT_TEST_DEVICES) -> None:
     repro_platform.set_host_device_count(n)
 
     if "jax" in sys.modules:
+        # starts the backend if nothing has yet, under the flag set above
         import jax
-        try:
-            initialized = jax._src.xla_bridge._backends  # noqa: SLF001
-        except AttributeError:  # private API moved: verify the hard way
-            initialized = True
-        if initialized and jax.local_device_count() < n:
+        if jax.local_device_count() < n:
             raise RuntimeError(
                 f"jax already initialized with {jax.local_device_count()} "
                 f"devices; force_host_devices({n}) must run before any jax "
@@ -44,15 +41,18 @@ def force_host_devices(n: int = DEFAULT_TEST_DEVICES) -> None:
 
 def enable_compilation_cache(cache_dir: str,
                              min_compile_secs: float = 0.5) -> None:
-    """Point jax's persistent compilation cache at `cache_dir`.
+    """Point jax's persistent compilation cache at `cache_dir`, unless
+    ``JAX_COMPILATION_CACHE_DIR`` already names one
+    (`repro.platform.use_compilation_cache`).
 
-    Set via environment (not jax.config) so subprocess children — the
+    Exported through the environment so subprocess children — the
     512-device mesh check, the quickstart example, benchmark respawns —
     share the same cache. Cuts repeat-run jit warm-up to ~1/5 on this
     suite; cold runs are unaffected. Respects pre-set env overrides.
     """
-    os.makedirs(cache_dir, exist_ok=True)
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    from repro import platform as repro_platform
+
+    repro_platform.use_compilation_cache(cache_dir)
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
                           str(min_compile_secs))
 
@@ -73,7 +73,8 @@ def sodda_test_mesh(cfg=None, P: int = 4, Q: int = 3):
     if cfg is not None:
         P, Q = cfg.P, cfg.Q
     require_host_devices(P * Q)
-    return jax.make_mesh((P, Q), ("data", "model"))
+    return jax.make_mesh((P, Q), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def run_forced_subprocess(script: str, devices: int, timeout: int = 560):

@@ -7,7 +7,6 @@ session) instead of each respawning a subprocess.
 """
 import os
 import sys
-import warnings
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -18,10 +17,6 @@ force_host_devices(12)
 enable_compilation_cache(
     os.path.join(os.path.dirname(__file__), "..", ".pytest_cache",
                  "jax_compilation_cache"))
-
-warnings.filterwarnings(
-    "ignore", message=".*default axis_types will change.*",
-    category=DeprecationWarning)
 
 
 def pytest_addoption(parser):

@@ -45,6 +45,32 @@ def test_t_returns_mean_us_per_call():
 # only when the device grid exists), and a backend that fails to lower on
 # the current platform degrades to a WARN row instead of aborting the bench.
 # ---------------------------------------------------------------------------
+_SPAWNING_CELLS = ("bench_distributed_sodda", "run_large_cell",
+                   "run_streaming_cell", "run_multihost_cell",
+                   "run_multihost_large_cell", "bench_driver_large",
+                   "bench_streaming", "bench_multihost",
+                   "bench_multihost_large")
+
+
+@pytest.mark.parametrize("cell", _SPAWNING_CELLS)
+def test_spawning_cells_refuse_on_accelerator(monkeypatch, cell):
+    """A cell whose children force CPU host devices refuses, by name,
+    before it spawns, when this process holds a chip."""
+    import subprocess
+
+    from repro.testing import multiprocess
+
+    def no_spawn(*args, **kwargs):
+        raise AssertionError("spawned a child on an accelerator host")
+
+    monkeypatch.setattr(subprocess, "run", no_spawn)
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    monkeypatch.setattr(multiprocess, "launch_coordinated", no_spawn)
+    monkeypatch.setattr(bench_run.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(bench_run.AcceleratorHeldError, match=cell):
+        getattr(bench_run, cell)()
+
+
 def test_resolve_driver_backends_covers_registry():
     from repro.core import engine
     from repro.testing import small_fixture_config

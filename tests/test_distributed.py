@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
 from repro.configs.sodda_svm import SoddaConfig
 from repro.core import engine, sodda
 from repro.core.distributed import (distributed_objective,
@@ -135,7 +134,7 @@ def test_compressed_psum_roundtrip():
     def f(x):
         return compressed_psum(x, "d")
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
                             out_specs=jax.sharding.PartitionSpec(),
                             check_vma=False))(x)
     # two quantizations, each with error <= scale/2 = absmax/254
@@ -146,7 +145,7 @@ def test_compressed_psum_roundtrip():
         out, ef2 = compressed_psum_ef(x, ef, "d")
         return out, ef2.residual
 
-    gj = jax.jit(shard_map(
+    gj = jax.jit(jax.shard_map(
         g, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
         out_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
@@ -166,7 +165,7 @@ def test_compressed_psum_multi_axis():
     from repro.optim.grad_compression import compressed_psum
     mesh = jax.make_mesh((1, 1), ("a", "b"))
     x = jax.random.normal(jax.random.PRNGKey(2), (64,))
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         lambda v: compressed_psum(v, ("a", "b")), mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(),
         out_specs=jax.sharding.PartitionSpec(), check_vma=False))(x)

@@ -42,7 +42,7 @@ def test_sodda_inner_ops_padding():
     Xl = jax.random.normal(k(6), (B, L, mt))
     yl = jnp.sign(jax.random.normal(k(7), (B, L)))
     mu = jax.random.normal(k(8), (B, mt)) * 0.01
-    out = ops.sodda_inner(w0, Xl, yl, mu, 0.05, "hinge", force="pallas")
+    out = ops.sodda_inner(w0, Xl, yl, mu, 0.05, "hinge")
     want = ref.sodda_inner_ref(w0, Xl, yl, mu, 0.05, "hinge")
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=1e-6)
 
@@ -76,8 +76,7 @@ def test_sodda_inner_blocked_vs_ref(loss, block_l):
     deliberately non-128-aligned mt (the ops padding path)."""
     B, L, mt = 2, 8, 130
     w0, Xl, yl, mu = _sodda_case(B, L, mt, 50)
-    out = ops.sodda_inner(w0, Xl, yl, mu, 0.04, loss, force="pallas",
-                          block_l=block_l)
+    out = ops.sodda_inner(w0, Xl, yl, mu, 0.04, loss, block_l=block_l)
     want = ref.sodda_inner_ref(w0, Xl, yl, mu, 0.04, loss)
     np.testing.assert_allclose(out, want, **_DERIV_TOL)
 
@@ -162,10 +161,9 @@ def test_interpret_flag_threaded_not_pinned(monkeypatch):
     # unique mt per call: jit only re-traces (and so only re-hits the spy)
     # on a fresh (shape, statics) cache key
     w0, Xl, yl, mu = _sodda_case(1, 4, 137, 90)
-    ops.sodda_inner(w0, Xl, yl, mu, 0.03, "hinge", force="pallas",
-                    interpret=True)
+    ops.sodda_inner(w0, Xl, yl, mu, 0.03, "hinge", interpret=True)
     w0, Xl, yl, mu = _sodda_case(1, 4, 139, 91)
-    ops.sodda_inner(w0, Xl, yl, mu, 0.03, "hinge", force="pallas")
+    ops.sodda_inner(w0, Xl, yl, mu, 0.03, "hinge")
     assert captured == [True, None]  # explicit passes through; None defers
 
 

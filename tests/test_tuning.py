@@ -55,8 +55,12 @@ def test_oversized_block_raises_vmem_budget_error():
 def test_vmem_bytes_accounts_double_buffering():
     cfg = BlockConfig(block_l=8)
     got = tuning.vmem_bytes(cfg, 64, 256)
-    want = (2 * 8 * 256 * 4) + (2 * 8 * 4) + (3 * 256 * 4) + (2 * 8 * 4)
+    # X and label streams, w0/mu/out blocks (each a (8, 256) tile), d0
+    want = (2 * 8 * 256 * 4) + (2 * 8 * 128 * 4) + (3 * 2 * 8 * 256 * 4) \
+        + (8 * 128 * 4)
     assert got == want
+    # rows round up to the 8-sublane tile: block_l=3 plans like block_l=8
+    assert tuning.vmem_bytes(BlockConfig(block_l=3), 12, 256) == got
 
 
 def test_padded_mt_rounds_to_lane():
