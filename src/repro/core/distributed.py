@@ -26,7 +26,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.sodda_svm import SoddaConfig
 from repro.core import losses
 from repro.core.partition import _exact_count_mask
-from repro.core.sodda import (AsyncSoddaState, SoddaState, _counts, _gamma,
+from repro.core.sodda import (CONSUME_SCOPE, EXCHANGE_SCOPE, ISSUE_SCOPE,
+                              AsyncSoddaState, SoddaState, _counts, _gamma,
                               inner_loop)
 
 __all__ = ["data_shardings", "make_distributed_step",
@@ -76,78 +77,92 @@ def make_local_halves(cfg: SoddaConfig, gather_deltas: bool = True,
     deriv = functools.partial(losses.loss_deriv, cfg.loss)
 
     def issue_local(X_loc, y_loc, w_loc, t, key):
-        p = jax.lax.axis_index("data")
-        q = jax.lax.axis_index("model")
-        kt = jax.random.fold_in(key, t)
-        kb, kd, _, _ = jax.random.split(kt, 4)
+        with jax.named_scope(ISSUE_SCOPE):
+            p = jax.lax.axis_index("data")
+            q = jax.lax.axis_index("model")
+            kt = jax.random.fold_in(key, t)
+            kb, kd, _, _ = jax.random.split(kt, 4)
 
-        # --- steps 5-7: B^t / C^t / D^t (B, C identical on all devices) ---
-        u = jax.random.uniform(kb, (M,))
-        mask_b = _exact_count_mask(u, b_count)
-        mask_c = _exact_count_mask(u, c_count)
-        mb_loc = jax.lax.dynamic_slice(mask_b, (q * m,), (m,))
-        mc_loc = jax.lax.dynamic_slice(mask_c, (q * m,), (m,))
-        ud = jax.random.uniform(jax.random.fold_in(kd, p), (n,))
-        md_loc = _exact_count_mask(ud, d_local)
+            # --- steps 5-7: B^t / C^t / D^t (B, C identical on all devices)
+            u = jax.random.uniform(kb, (M,))
+            mask_b = _exact_count_mask(u, b_count)
+            mask_c = _exact_count_mask(u, c_count)
+            mb_loc = jax.lax.dynamic_slice(mask_b, (q * m,), (m,))
+            mc_loc = jax.lax.dynamic_slice(mask_c, (q * m,), (m,))
+            ud = jax.random.uniform(jax.random.fold_in(kd, p), (n,))
+            md_loc = _exact_count_mask(ud, d_local)
 
-        # --- step 8: stochastic snapshot gradient ---
-        z_part = X_loc @ (w_loc * mb_loc)  # (n,)
-        if compress_z:
-            # §Perf iteration 2: the z = x_j^B w_B partial-sum reduction over
-            # 'model' is the DOMINANT collective of a SODDA iteration (d*n
-            # scalars/device vs m for mu) — int8 wires cut it 4x; the margin
-            # error feeds an already-stochastic snapshot estimator.
-            from repro.optim.grad_compression import compressed_psum
-            z = compressed_psum(z_part, "model")
-        else:
-            z = jax.lax.psum(z_part, "model")
-        s = deriv(z, y_loc) * md_loc / (cfg.P * d_local)
-        mu_part = mc_loc * (X_loc.T @ s)
-        if compress_mu:
-            from repro.optim.grad_compression import compressed_psum
-            mu_q = compressed_psum(mu_part, "data")  # int8 wires, f32 out
-        else:
-            mu_q = jax.lax.psum(mu_part, "data")  # (m,)
-        return mu_q
+            # --- step 8: stochastic snapshot gradient ---
+            z_part = X_loc @ (w_loc * mb_loc)  # (n,)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                if compress_z:
+                    # §Perf iteration 2: the z = x_j^B w_B partial-sum
+                    # reduction over 'model' is the DOMINANT collective of a
+                    # SODDA iteration (d*n scalars/device vs m for mu) —
+                    # int8 wires cut it 4x; the margin error feeds an
+                    # already-stochastic snapshot estimator.
+                    from repro.optim.grad_compression import compressed_psum
+                    z = compressed_psum(z_part, "model")
+                else:
+                    z = jax.lax.psum(z_part, "model")
+            s = deriv(z, y_loc) * md_loc / (cfg.P * d_local)
+            mu_part = mc_loc * (X_loc.T @ s)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                if compress_mu:
+                    from repro.optim.grad_compression import compressed_psum
+                    mu_q = compressed_psum(mu_part, "data")  # int8, f32 out
+                else:
+                    mu_q = jax.lax.psum(mu_part, "data")  # (m,)
+            return mu_q
 
     def consume_local(X_loc, y_loc, w_loc, mu_q, t, key):
-        p = jax.lax.axis_index("data")
-        q = jax.lax.axis_index("model")
-        gamma = _gamma(cfg, t)
-        kt = jax.random.fold_in(key, t)
-        _, _, kp, kj = jax.random.split(kt, 4)
+        with jax.named_scope(CONSUME_SCOPE):
+            p = jax.lax.axis_index("data")
+            q = jax.lax.axis_index("model")
+            gamma = _gamma(cfg, t)
+            kt = jax.random.fold_in(key, t)
+            _, _, kp, kj = jax.random.split(kt, 4)
 
-        # --- step 10: pi_q block assignment (one sub-block per worker) ---
-        pi_q = jax.random.permutation(jax.random.fold_in(kp, q), cfg.P)
-        k = pi_q[p]
+            # --- step 10: pi_q block assignment (one sub-block per worker)
+            pi_q = jax.random.permutation(jax.random.fold_in(kp, q), cfg.P)
+            k = pi_q[p]
 
-        # --- steps 13-17: fully local inner loop ---
-        J = jax.random.randint(jax.random.fold_in(kj, p * cfg.Q + q), (L,), 0, n)
-        X_blk = jax.lax.dynamic_slice(X_loc, (0, k * mt), (n, mt))
-        Xl = X_blk[J]
-        yl = y_loc[J]
-        w0 = jax.lax.dynamic_slice(w_loc, (k * mt,), (mt,))
-        mu_blk = jax.lax.dynamic_slice(mu_q, (k * mt,), (mt,))
-        if use_kernel:
-            from repro.kernels import ops as kops  # local import: optional dep
-            wL = kops.sodda_inner(w0[None], Xl[None], yl[None], mu_blk[None],
-                                  gamma, cfg.loss, block_l=block_l)[0]
-        else:
-            wL = inner_loop(cfg.loss, w0, Xl, yl, mu_blk, gamma)
+            # --- steps 13-17: fully local inner loop ---
+            J = jax.random.randint(jax.random.fold_in(kj, p * cfg.Q + q),
+                                   (L,), 0, n)
+            X_blk = jax.lax.dynamic_slice(X_loc, (0, k * mt), (n, mt))
+            Xl = X_blk[J]
+            yl = y_loc[J]
+            w0 = jax.lax.dynamic_slice(w_loc, (k * mt,), (mt,))
+            mu_blk = jax.lax.dynamic_slice(mu_q, (k * mt,), (mt,))
+            if use_kernel:
+                from repro.kernels import ops as kops  # local: optional dep
+                wL = kops.sodda_inner(w0[None], Xl[None], yl[None],
+                                      mu_blk[None], gamma, cfg.loss,
+                                      block_l=block_l)[0]
+            else:
+                wL = inner_loop(cfg.loss, w0, Xl, yl, mu_blk, gamma)
 
-        # --- step 19: assemble. Each (q, k) block was updated by exactly one
-        # row; share the new blocks across the column.
-        if gather_deltas:
-            # all_gather the (owner_row, block) pairs then scatter locally:
-            # volume (P-1)/P * m per device, half of the psum variant.
-            blocks = jax.lax.all_gather(wL, "data")  # (P, mt) — row r's block
-            ks = jax.lax.all_gather(k, "data")  # (P,) — row r updated block ks[r]
-            w_new = w_loc.reshape(cfg.P, mt).at[ks].set(blocks).reshape(m)
-        else:
-            delta = jnp.zeros((m,), w_loc.dtype)
-            delta = jax.lax.dynamic_update_slice(delta, wL - w0, (k * mt,))
-            w_new = w_loc + jax.lax.psum(delta, "data")
-        return w_new
+            # --- step 19: assemble. Each (q, k) block was updated by exactly
+            # one row; share the new blocks across the column.
+            if gather_deltas:
+                # all_gather the (owner_row, block) pairs then scatter
+                # locally: volume (P-1)/P * m per device, half of the psum
+                # variant.
+                with jax.named_scope(EXCHANGE_SCOPE):
+                    blocks = jax.lax.all_gather(wL, "data")  # (P, mt)
+                    ks = jax.lax.all_gather(k, "data")  # (P,)
+                # row r's block is blocks[r], and it updated block ks[r]
+                w_new = w_loc.reshape(cfg.P, mt).at[ks].set(blocks) \
+                    .reshape(m)
+            else:
+                delta = jnp.zeros((m,), w_loc.dtype)
+                delta = jax.lax.dynamic_update_slice(delta, wL - w0,
+                                                     (k * mt,))
+                with jax.named_scope(EXCHANGE_SCOPE):
+                    delta = jax.lax.psum(delta, "data")
+                w_new = w_loc + delta
+            return w_new
 
     return issue_local, consume_local
 

@@ -95,6 +95,7 @@ import numpy as np
 
 from repro.configs.sodda_svm import SoddaConfig
 from repro.core import losses
+from repro.core.sodda import OBJECTIVE_SCOPE
 
 __all__ = ["record_ticks", "make_run", "place_initial_state", "run",
            "run_resumable", "migrate_resumable", "replay_segment",
@@ -113,6 +114,12 @@ def record_ticks(iters: int, record_every: int) -> Tuple[int, ...]:
     if record_every < 1:
         raise ValueError(f"record_every must be >= 1, got {record_every}")
     return tuple(range(0, iters, record_every)) + (iters,)
+
+
+def _recorded_objective(loss: str, X, y, w):
+    """F(w) as the scan programs record it, under the objective's scope."""
+    with jax.named_scope(OBJECTIVE_SCOPE):
+        return losses.objective(loss, X, y, w)
 
 
 def _chunk_lengths(iters: int, record_every: int) -> Tuple[int, ...]:
@@ -135,7 +142,7 @@ def _cached_run(cfg: SoddaConfig, iters: int, backend: str, record_every: int,
     from repro.core import engine  # local: engine imports core.sodda
 
     bundle = engine.make_bundle(cfg, backend, mesh=mesh, **dict(options))
-    obj = functools.partial(losses.objective, cfg.loss)
+    obj = functools.partial(_recorded_objective, cfg.loss)
     lens = jnp.asarray(_chunk_lengths(iters, record_every), jnp.int32)
 
     def _run(state, X, y):
@@ -397,7 +404,7 @@ def _cached_segment_run(cfg: SoddaConfig, seg_iters: int, backend: str,
     from repro.core import engine
 
     bundle = engine.make_bundle(cfg, backend, mesh=mesh, **dict(options))
-    obj = functools.partial(losses.objective, cfg.loss)
+    obj = functools.partial(_recorded_objective, cfg.loss)
 
     def chunk(c, length, X, y):
         f = obj(X, y, c.w)

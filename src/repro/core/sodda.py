@@ -20,7 +20,20 @@ from repro.core.partition import IterationSample, sample_iteration
 
 __all__ = ["SoddaState", "AsyncSoddaState", "init_state", "init_async_state",
            "sodda_step", "sodda_step_async", "consume_update", "run",
-           "snapshot_gradient", "inner_loop", "iteration_flops"]
+           "snapshot_gradient", "inner_loop", "iteration_flops",
+           "ISSUE_SCOPE", "EXCHANGE_SCOPE", "CONSUME_SCOPE",
+           "OBJECTIVE_SCOPE"]
+
+# The stages of an outer iteration, as ``jax.named_scope`` names. A scope
+# writes only the ``op_name`` metadata of the HLO instructions traced under
+# it (``.../sodda.issue/dot_general``), so a profile of any compiled run
+# splits device time by stage at no run-time cost. An instruction's stage is
+# its innermost scope: the exchange's collectives sit inside the issue and
+# consume halves.
+ISSUE_SCOPE = "sodda.issue"  # sample draw, the snapshot gradient's passes
+EXCHANGE_SCOPE = "sodda.exchange"  # the mesh step's collectives
+CONSUME_SCOPE = "sodda.consume"  # row gather, inner chains, assembly
+OBJECTIVE_SCOPE = "sodda.objective"  # the driver's recorded F(w)
 
 
 class SoddaState(NamedTuple):
@@ -118,9 +131,10 @@ def _issue(cfg: SoddaConfig, X, y, w, t, key):
     invariant depends on all three issuing identically.
     """
     b_count, c_count, d_local = _counts(cfg)
-    smp = sample_iteration(key, t, cfg.P, cfg.Q, cfg.n, cfg.M, cfg.L,
-                           b_count, c_count, d_local)
-    mu = snapshot_gradient(cfg.loss, X, y, w, smp, cfg.P * d_local)
+    with jax.named_scope(ISSUE_SCOPE):
+        smp = sample_iteration(key, t, cfg.P, cfg.Q, cfg.n, cfg.M, cfg.L,
+                               b_count, c_count, d_local)
+        mu = snapshot_gradient(cfg.loss, X, y, w, smp, cfg.P * d_local)
     return smp, mu
 
 
@@ -137,40 +151,43 @@ def consume_update(X, y, w, mu, smp: IterationSample, gamma,
     """
     P, Q, n, M, L = cfg.P, cfg.Q, cfg.n, cfg.M, cfg.L
     mt = cfg.m_tilde
+    with jax.named_scope(CONSUME_SCOPE):
+        # gather per-(p,q) working sets ------------------------------------
+        Xb = X.reshape(P, n, Q * P, mt).transpose(0, 2, 1, 3)  # (P,QP,n,mt)
+        yb = y.reshape(P, n)
+        wb = w.reshape(Q, P, mt)
+        mub = mu.reshape(Q, P, mt)
 
-    # gather per-(p,q) working sets ----------------------------------------
-    Xb = X.reshape(P, n, Q * P, mt).transpose(0, 2, 1, 3)  # (P, QP, n, mt)
-    yb = y.reshape(P, n)
-    wb = w.reshape(Q, P, mt)
-    mub = mu.reshape(Q, P, mt)
+        pq_p, pq_q = jnp.meshgrid(jnp.arange(P), jnp.arange(Q),
+                                  indexing="ij")
 
-    pq_p, pq_q = jnp.meshgrid(jnp.arange(P), jnp.arange(Q), indexing="ij")
+        def gather_one(p, q):
+            k = smp.pi[q, p]
+            rows = smp.J[p, q]  # (L,)
+            Xl = Xb[p, q * P + k][rows]  # (L, mt)
+            yl = yb[p][rows]
+            return Xl, yl, wb[q, k], mub[q, k]
 
-    def gather_one(p, q):
-        k = smp.pi[q, p]
-        rows = smp.J[p, q]  # (L,)
-        Xl = Xb[p, q * P + k][rows]  # (L, mt)
-        yl = yb[p][rows]
-        return Xl, yl, wb[q, k], mub[q, k]
+        Xl, yl, w0, mu_blk = jax.vmap(jax.vmap(gather_one))(pq_p, pq_q)
 
-    Xl, yl, w0, mu_blk = jax.vmap(jax.vmap(gather_one))(pq_p, pq_q)
+        if use_kernel:
+            from repro.kernels import ops as kops  # local: optional dep
+            wL = kops.sodda_inner(
+                w0.reshape(P * Q, mt), Xl.reshape(P * Q, L, mt),
+                yl.reshape(P * Q, L), mu_blk.reshape(P * Q, mt),
+                gamma, cfg.loss, block_l=block_l).reshape(P, Q, mt)
+        else:
+            wL = jax.vmap(jax.vmap(
+                lambda w_, X_, y_, m_: inner_loop(cfg.loss, w_, X_, y_, m_,
+                                                  gamma)
+            ))(w0, Xl, yl, mu_blk)
 
-    if use_kernel:
-        from repro.kernels import ops as kops  # local import: optional dep
-        wL = kops.sodda_inner(
-            w0.reshape(P * Q, mt), Xl.reshape(P * Q, L, mt),
-            yl.reshape(P * Q, L), mu_blk.reshape(P * Q, mt),
-            gamma, cfg.loss, block_l=block_l).reshape(P, Q, mt)
-    else:
-        wL = jax.vmap(jax.vmap(
-            lambda w_, X_, y_, m_: inner_loop(cfg.loss, w_, X_, y_, m_, gamma)
-        ))(w0, Xl, yl, mu_blk)
-
-    # step 19: conflict-free concatenation — each (q, pi_q(p)) written once
-    q_idx = jnp.repeat(jnp.arange(Q), P)
-    k_idx = smp.pi.reshape(-1)
-    new_wb = wb.at[q_idx, k_idx].set(wL.transpose(1, 0, 2).reshape(Q * P, mt))
-    return new_wb.reshape(M)
+        # step 19: conflict-free concatenation, each (q, pi_q(p)) once
+        q_idx = jnp.repeat(jnp.arange(Q), P)
+        k_idx = smp.pi.reshape(-1)
+        new_wb = wb.at[q_idx, k_idx].set(
+            wL.transpose(1, 0, 2).reshape(Q * P, mt))
+        return new_wb.reshape(M)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "use_kernel", "block_l"))

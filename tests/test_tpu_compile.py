@@ -10,6 +10,7 @@ Table 1 SMALL (250,000 x 18,000). The topology is described inside a
 module fixture, so only the worker that runs this file loads libtpu.
 """
 import os
+import re
 import sys
 
 import jax
@@ -19,7 +20,7 @@ import pytest
 
 from repro import platform as repro_platform
 from repro.core import driver
-from repro.core.sodda import SoddaState
+from repro.core.sodda import CONSUME_SCOPE, SoddaState
 from repro.kernels import ops, tuning
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -109,7 +110,12 @@ def _run_program(cfg, backend, mesh=None, one=None):
                           record_every=chip_smoke.RECORD_EVERY, mesh=mesh)
     compiled = run.lower(state, _sds((cfg.N, cfg.M), f32, x_sh),
                          _sds((cfg.N,), f32, y_sh)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels
+    for line in kernels:  # the Mosaic kernel is timed as the consume half
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.findall(r"sodda\.\w+", op_name)[-1] == CONSUME_SCOPE
     mem = compiled.memory_analysis()
     return mem.argument_size_in_bytes + mem.temp_size_in_bytes
 
