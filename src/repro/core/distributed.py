@@ -130,8 +130,9 @@ def make_local_halves(cfg: SoddaConfig, gather_deltas: bool = True,
             # --- steps 13-17: fully local inner loop ---
             J = jax.random.randint(jax.random.fold_in(kj, p * cfg.Q + q),
                                    (L,), 0, n)
-            X_blk = jax.lax.dynamic_slice(X_loc, (0, k * mt), (n, mt))
-            Xl = X_blk[J]
+            # the L sampled rows first, then the sub-block's columns: the
+            # chip's n x mt sub-block is never copied
+            Xl = jax.lax.dynamic_slice(X_loc[J], (0, k * mt), (L, mt))
             yl = y_loc[J]
             w0 = jax.lax.dynamic_slice(w_loc, (k * mt,), (mt,))
             mu_blk = jax.lax.dynamic_slice(mu_q, (k * mt,), (mt,))

@@ -19,8 +19,8 @@ from repro.core import losses
 from repro.core.partition import IterationSample, sample_iteration
 
 __all__ = ["SoddaState", "AsyncSoddaState", "init_state", "init_async_state",
-           "sodda_step", "sodda_step_async", "consume_update", "run",
-           "snapshot_gradient", "inner_loop", "iteration_flops",
+           "sodda_step", "sodda_step_async", "working_sets", "consume_update",
+           "run", "snapshot_gradient", "inner_loop", "iteration_flops",
            "ISSUE_SCOPE", "EXCHANGE_SCOPE", "CONSUME_SCOPE",
            "OBJECTIVE_SCOPE"]
 
@@ -138,6 +138,32 @@ def _issue(cfg: SoddaConfig, X, y, w, t, key):
     return smp, mu
 
 
+def working_sets(X, y, w, mu, smp: IterationSample, cfg: SoddaConfig):
+    """Steps 10-13: every worker's inner-loop inputs, read where they lie.
+
+    Worker (p, q) updates sub-block q*P + pi_q(p), columns
+    [(q*P + pi_q(p)) * mt, +mt) of X, from rows p*n + J[p, q]. One gather
+    of (1, mt) slices takes those P*Q*L rows from X as laid out: X is never
+    reshaped or copied, and no worker's n x mt sub-block is materialised.
+    Returns Xl (P, Q, L, mt), yl (P, Q, L), w0 and mu_blk (P, Q, mt).
+    """
+    P, Q, n, L, mt = cfg.P, cfg.Q, cfg.n, cfg.L, cfg.m_tilde
+    blk = jnp.arange(Q) * P + smp.pi.T  # (P, Q)
+    rows = jnp.arange(P)[:, None, None] * n + smp.J  # (P, Q, L)
+    cols = jnp.broadcast_to((blk * mt)[:, :, None], rows.shape)
+    starts = jnp.stack([rows, cols], axis=-1).reshape(P * Q * L, 2)
+    Xl = jax.lax.gather(
+        X, starts,
+        jax.lax.GatherDimensionNumbers(offset_dims=(1,),
+                                       collapsed_slice_dims=(0,),
+                                       start_index_map=(0, 1)),
+        slice_sizes=(1, mt),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    ).reshape(P, Q, L, mt)
+    return (Xl, y[rows], w.reshape(Q * P, mt)[blk],
+            mu.reshape(Q * P, mt)[blk])
+
+
 def consume_update(X, y, w, mu, smp: IterationSample, gamma,
                    cfg: SoddaConfig, use_kernel: bool = False,
                    block_l=None):
@@ -149,26 +175,10 @@ def consume_update(X, y, w, mu, smp: IterationSample, gamma,
     concatenates the updated sub-blocks into the new iterate. Fully local:
     on a mesh nothing here needs a collective except the final concatenate.
     """
-    P, Q, n, M, L = cfg.P, cfg.Q, cfg.n, cfg.M, cfg.L
+    P, Q, M, L = cfg.P, cfg.Q, cfg.M, cfg.L
     mt = cfg.m_tilde
     with jax.named_scope(CONSUME_SCOPE):
-        # gather per-(p,q) working sets ------------------------------------
-        Xb = X.reshape(P, n, Q * P, mt).transpose(0, 2, 1, 3)  # (P,QP,n,mt)
-        yb = y.reshape(P, n)
-        wb = w.reshape(Q, P, mt)
-        mub = mu.reshape(Q, P, mt)
-
-        pq_p, pq_q = jnp.meshgrid(jnp.arange(P), jnp.arange(Q),
-                                  indexing="ij")
-
-        def gather_one(p, q):
-            k = smp.pi[q, p]
-            rows = smp.J[p, q]  # (L,)
-            Xl = Xb[p, q * P + k][rows]  # (L, mt)
-            yl = yb[p][rows]
-            return Xl, yl, wb[q, k], mub[q, k]
-
-        Xl, yl, w0, mu_blk = jax.vmap(jax.vmap(gather_one))(pq_p, pq_q)
+        Xl, yl, w0, mu_blk = working_sets(X, y, w, mu, smp, cfg)
 
         if use_kernel:
             from repro.kernels import ops as kops  # local: optional dep
@@ -185,7 +195,7 @@ def consume_update(X, y, w, mu, smp: IterationSample, gamma,
         # step 19: conflict-free concatenation, each (q, pi_q(p)) once
         q_idx = jnp.repeat(jnp.arange(Q), P)
         k_idx = smp.pi.reshape(-1)
-        new_wb = wb.at[q_idx, k_idx].set(
+        new_wb = w.reshape(Q, P, mt).at[q_idx, k_idx].set(
             wL.transpose(1, 0, 2).reshape(Q * P, mt))
         return new_wb.reshape(M)
 

@@ -226,3 +226,84 @@ def test_kernel_path_matches_reference(data):
     w_ref = sodda.sodda_step(s0, X, y, CFG, use_kernel=False).w
     w_ker = sodda.sodda_step(s0, X, y, CFG, use_kernel=True).w
     np.testing.assert_allclose(w_ref, w_ker, rtol=2e-5, atol=1e-6)
+
+
+def _working_sets_oracle(X, y, w, mu, smp, cfg):
+    """The consume half's gather as it was first written: X re-laid out to
+    (P, QP, n, mt), then a double vmap over the worker grid."""
+    P, Q, n, mt = cfg.P, cfg.Q, cfg.n, cfg.m_tilde
+    Xb = X.reshape(P, n, Q * P, mt).transpose(0, 2, 1, 3)
+    yb = y.reshape(P, n)
+    wb = w.reshape(Q, P, mt)
+    mub = mu.reshape(Q, P, mt)
+    pq_p, pq_q = jnp.meshgrid(jnp.arange(P), jnp.arange(Q), indexing="ij")
+
+    def gather_one(p, q):
+        k = smp.pi[q, p]
+        rows = smp.J[p, q]
+        return Xb[p, q * P + k][rows], yb[p][rows], wb[q, k], mub[q, k]
+
+    return jax.vmap(jax.vmap(gather_one))(pq_p, pq_q)
+
+
+def _consume_oracle(X, y, w, mu, smp, gamma, cfg):
+    P, Q, M, mt = cfg.P, cfg.Q, cfg.M, cfg.m_tilde
+    Xl, yl, w0, mu_blk = _working_sets_oracle(X, y, w, mu, smp, cfg)
+    wL = jax.vmap(jax.vmap(
+        lambda w_, X_, y_, m_: sodda.inner_loop(cfg.loss, w_, X_, y_, m_,
+                                                gamma)))(w0, Xl, yl, mu_blk)
+    q_idx = jnp.repeat(jnp.arange(Q), P)
+    new_wb = w.reshape(Q, P, mt).at[q_idx, smp.pi.reshape(-1)].set(
+        wL.transpose(1, 0, 2).reshape(Q * P, mt))
+    return new_wb.reshape(M)
+
+
+# (P, Q, n, m, L): one worker; Table 1's 5x3 grid with mt = 130, not a
+# multiple of 128; L > n, so rows are drawn with repeats
+GATHER_CASES = [(1, 1, 8, 4, 5), (5, 3, 12, 650, 7), (2, 3, 3, 10, 16)]
+
+
+def _gather_problem(P, Q, n, m, L, seed):
+    cfg = SoddaConfig(P=P, Q=Q, n=n, m=m, L=L, lr0=0.05)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    X = jax.random.normal(ks[0], (cfg.N, cfg.M))
+    y = jnp.sign(jax.random.normal(ks[1], (cfg.N,)))
+    w = jax.random.normal(ks[2], (cfg.M,))
+    mu = jax.random.normal(ks[3], (cfg.M,))
+    return cfg, X, y, w, mu
+
+
+def _sample(cfg, seed, t):
+    b, c, d = sodda._counts(cfg)
+    return sample_iteration(jax.random.PRNGKey(seed), t, cfg.P, cfg.Q, cfg.n,
+                            cfg.M, cfg.L, b, c, d)
+
+
+@pytest.mark.parametrize("P,Q,n,m,L", GATHER_CASES)
+def test_working_sets_gather_in_place_is_bitwise(P, Q, n, m, L):
+    """The working sets taken where they lie in X are the re-laid-out
+    copy's, float for float, over several random samples."""
+    cfg, X, y, w, mu = _gather_problem(P, Q, n, m, L, seed=20 + P)
+    new = jax.jit(sodda.working_sets, static_argnames="cfg")
+    old = jax.jit(_working_sets_oracle, static_argnames="cfg")
+    for t in range(1, 5):
+        smp = _sample(cfg, 30 + P, t)
+        if L > n:
+            assert len(np.unique(np.asarray(smp.J[0, 0]))) < L
+        got, want = new(X, y, w, mu, smp, cfg), old(X, y, w, mu, smp, cfg)
+        for g, e in zip(got, want):
+            assert g.shape == e.shape
+            np.testing.assert_array_equal(g, e)
+
+
+def test_consume_update_matches_relayout_oracle_bitwise():
+    """One reference-path consume half gives the iterate the re-laid-out
+    gather gave, bit for bit."""
+    cfg, X, y, w, mu = _gather_problem(*GATHER_CASES[1], seed=40)
+    smp = _sample(cfg, 41, 3)
+    gamma = sodda._gamma(cfg, jnp.int32(3))
+    got = jax.jit(sodda.consume_update, static_argnames="cfg")(
+        X, y, w, mu, smp, gamma, cfg)
+    want = jax.jit(_consume_oracle, static_argnames="cfg")(
+        X, y, w, mu, smp, gamma, cfg)
+    np.testing.assert_array_equal(got, want)
