@@ -93,9 +93,14 @@ def _rmatvec(X, s, dtype):
 
 @functools.partial(jax.jit, static_argnames=("mt",))
 def _gather(X, rows, col0, mt):
-    """X[rows[b], col0[b]:col0[b]+mt] for each block b of one tile."""
-    return jax.vmap(lambda r, c: jax.lax.dynamic_slice_in_dim(
-        X[r], c, mt, axis=1))(rows, col0)
+    """X[rows[b], col0[b]:col0[b]+mt] for each block b of one tile.
+
+    One gather of single elements, which reads the tile in whichever layout
+    it lies: the TPU lays a tall tile out column-major, and a gather of whole
+    rows or of (1, mt) slices first copies the whole tile to row-major.
+    """
+    cols = col0[:, None] + jnp.arange(mt, dtype=col0.dtype)
+    return X[rows[:, :, None], cols[:, None, :]]
 
 
 @functools.partial(jax.jit, static_argnames=("loss", "dtype"))
